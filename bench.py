@@ -56,15 +56,18 @@ def _serving_arm(rail: str, codec: str, duration_s: float) -> dict:
     """One serving-bench arm (ISSUE 14): in-process ServingEngine +
     Server driven by rpc_press.press_stream.  Runs in its OWN subprocess
     (the PJRT client and the jit caches are process-global), so env must
-    be staged before the jax import chain."""
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        (os.environ.get("XLA_FLAGS", "") +
-         " --xla_force_host_platform_device_count=8").strip())
-    fake = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "brpc_tpu", "_native", "libpjrt_fake.so")
-    if os.path.exists(fake):
-        os.environ.setdefault("TRPC_PJRT_PLUGIN", fake)
+    be staged before the jax import chain.  Under JAX_PLATFORMS=cpu it
+    runs on an 8-device virtual mesh with the fake PJRT plugin; anywhere
+    else on the chip's devices with the installed libtpu."""
+    cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
+    if cpu:
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            (os.environ.get("XLA_FLAGS", "") +
+             " --xla_force_host_platform_device_count=8").strip())
+        os.environ.setdefault("TRPC_PJRT_PLUGIN", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)),
+            "brpc_tpu", "_native", "libpjrt_fake.so"))
     from brpc_tpu import tpu_plane
     from brpc_tpu.parallel.mesh import make_mesh
     from brpc_tpu.rpc.channel import Channel, ChannelOptions
@@ -74,9 +77,11 @@ def _serving_arm(rail: str, codec: str, duration_s: float) -> dict:
     from brpc_tpu.serving.kv_cache import KvBlockPlane
     from brpc_tpu.tools.rpc_press import press_stream
 
-    plane = tpu_plane.init()
+    if not tpu_plane.init(timeout_s=120):
+        raise RuntimeError(f"device plane down: {tpu_plane.error()}")
     engine = ServingEngine(
-        cfg=tiny_config(), mesh=make_mesh({"dp": 2, "tp": 4}),
+        cfg=tiny_config(),
+        mesh=make_mesh({"dp": 2, "tp": 4}) if cpu else None,
         kv=KvBlockPlane(block_bytes=4096, n_blocks=48,
                         rail=rail, codec=codec),
         n_slots=4, max_waiting=4)
@@ -107,7 +112,7 @@ def _serving_arm(rail: str, codec: str, duration_s: float) -> dict:
     server.destroy()
     return {
         "metric": "serving_bench", "rail": rail, "codec": codec,
-        "plane": plane, "duration_s": round(res.wall_s, 2),
+        "plane": tpu_plane.platform(), "duration_s": round(res.wall_s, 2),
         "streams": res.streams, "completed": res.completed,
         "shed": res.shed, "resets": res.resets, "errors": res.errors,
         "tokens": res.tokens,
@@ -125,7 +130,8 @@ def _serving_arm(rail: str, codec: str, duration_s: float) -> dict:
 
 
 def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # bench is host-side
+    # the echo bench never imports JAX; the --serving parent stays off it
+    # too (its arm subprocesses own the chip, one at a time)
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import ctypes
 
@@ -173,7 +179,7 @@ def main() -> int:
                          "admitted-only TTFT/ITL from rpc_press "
                          "--stream against the continuous-batching "
                          "engine, with the KV-migration rail/codec A/B "
-                         "(auto-rail headline, then host-rail "
+                         "(local-rail headline, then host-rail "
                          "none/bf16/int8; one subprocess per arm: the "
                          "PJRT client is process-global)")
     ap.add_argument("--serving-arm", default="",
@@ -190,7 +196,7 @@ def main() -> int:
     if args.serving:
         me = os.path.abspath(__file__)
         table = {}
-        for rail, codec in (("auto", "none"), ("host", "none"),
+        for rail, codec in (("local", "none"), ("host", "none"),
                             ("host", "bf16"), ("host", "int8")):
             key = f"{rail}/{codec}"
             try:
@@ -206,7 +212,7 @@ def main() -> int:
                 table[key] = json.loads(r.stdout.strip().splitlines()[-1])
             except Exception as e:  # noqa: BLE001 — arm -> error cell
                 table[key] = {"error": str(e)}
-        head = table.get("auto/none", {})
+        head = table.get("local/none", {})
         print(json.dumps({"metric": "serving_ab",
                           "value": head.get("tokens_per_s"),
                           "unit": "tokens/s", "table": table}))

@@ -1,9 +1,10 @@
 """ctypes loader for the native core (libbrpc_tpu_core.so).
 
-Builds the library on first import if it is missing (cmake+ninja via
-native/build.sh).  The native core provides the hot paths: IOBuf, the M:N
-fiber scheduler with butex (≙ reference src/bthread), and — as later layers
-land — sockets, the TRPC wire protocol, and the in-process bench loops.
+Builds the library on first load if it is missing (cmake+ninja via
+native/build.sh, which takes an inter-process file lock in native/build).
+The native core provides the hot paths: IOBuf, the M:N fiber scheduler with
+butex (≙ reference src/bthread), and — as later layers land — sockets,
+the TRPC wire protocol, and the in-process bench loops.
 """
 
 from __future__ import annotations
@@ -35,8 +36,15 @@ HTTP_CHUNK_CB = ctypes.CFUNCTYPE(
 
 
 def _build() -> None:
+    """Build via native/build.sh, which holds an inter-process lock on
+    native/build/.build.lock: processes that first-load together build
+    once; the rest wait, find nothing to do, and load the same library."""
     script = os.path.join(_REPO, "native", "build.sh")
-    subprocess.run(["bash", script], check=True, capture_output=True)
+    r = subprocess.run(["bash", script], capture_output=True, text=True)
+    if r.returncode != 0 or not os.path.exists(_SO):
+        raise RuntimeError(
+            f"native build failed (rc={r.returncode}):\n"
+            f"{r.stdout[-4000:]}{r.stderr[-4000:]}")
 
 
 def lib() -> ctypes.CDLL:
@@ -595,6 +603,8 @@ def _declare(L: ctypes.CDLL) -> None:
     L.trpc_tpu_d2d.argtypes = [c.c_uint64, c.c_int]
     L.trpc_tpu_d2d.restype = c.c_uint64
     L.trpc_tpu_plane_uid.restype = c.c_uint64
+    L.trpc_tpu_plane_api_version.argtypes = [c.POINTER(c.c_int)]
+    L.trpc_tpu_plane_api_version.restype = None
     L.trpc_stream_write_device.argtypes = [c.c_uint64, c.c_uint64,
                                            c.c_int64]
     L.trpc_stream_write_device.restype = c.c_int

@@ -14,6 +14,7 @@ from brpc_tpu.models.transformer import (  # noqa: F401
     apply,
     init,
     param_specs,
+    place_params,
 )
 from brpc_tpu.models.decode import (  # noqa: F401
     decode_step,

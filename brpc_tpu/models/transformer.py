@@ -149,6 +149,17 @@ def param_specs(cfg: ModelConfig) -> Dict:
     return specs
 
 
+def place_params(params: Dict, cfg: ModelConfig, mesh: Mesh) -> Dict:
+    """Put a param tree on `mesh` with the param_specs layout (axes the
+    mesh lacks replicate) — init() leaves every leaf on the default
+    device, so an unplaced tree would start the whole model on device 0."""
+    from brpc_tpu.parallel.mesh import prune_spec
+    return jax.tree.map(
+        lambda x, s: jax.device_put(
+            x, NamedSharding(mesh, prune_spec(s, mesh))),
+        params, param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+
+
 # ---------------------------------------------------------------------------
 # forward
 

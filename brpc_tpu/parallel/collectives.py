@@ -21,14 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from brpc_tpu.utils.jaxenv import shard_map_compat
-
-shard_map, _SHMAP_NOCHECK = shard_map_compat()
-
 
 def _shmap(mesh: Mesh, axis: str, body: Callable, in_spec, out_spec):
-    return shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                     **_SHMAP_NOCHECK)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                         out_specs=out_spec, check_vma=False)
 
 
 @lru_cache(maxsize=None)

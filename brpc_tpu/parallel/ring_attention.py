@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from brpc_tpu.utils.jaxenv import shard_map_compat
 
 # "masked" logit: finite so the online max stays NaN-free even for
 # fully-masked blocks.  np.float32, NOT jnp: this module is imported
@@ -111,10 +110,10 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         scale = 1.0 / (q.shape[-1] ** 0.5)
     spec = P("dp" if "dp" in mesh.axis_names else None, axis,
              "tp" if "tp" in mesh.axis_names else None, None)
-    shmap, nocheck = shard_map_compat()
-    fn = shmap(
+    fn = jax.shard_map(
         partial(_ring_shard, axis=axis, causal=causal, scale=scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **nocheck)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -159,8 +158,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         scale = 1.0 / (q.shape[-1] ** 0.5)
     spec = P("dp" if "dp" in mesh.axis_names else None, axis,
              "tp" if "tp" in mesh.axis_names else None, None)
-    shmap, nocheck = shard_map_compat()
-    fn = shmap(
+    fn = jax.shard_map(
         partial(_ulysses_shard, axis=axis, causal=causal, scale=scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **nocheck)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)

@@ -79,6 +79,9 @@ class ServingEngine:
         self.mesh = mesh
         self.params = params if params is not None else transformer.init(
             jax.random.PRNGKey(seed), self.cfg)
+        if mesh is not None:
+            self.params = transformer.place_params(self.params, self.cfg,
+                                                   mesh)
         self.n_slots = n_slots or flags.get_flag("serving_slots")
         self.max_len = max_len or self.cfg.max_seq
         self.kv = kv or KvBlockPlane()

@@ -52,10 +52,11 @@ flags.define_int32(
     reloadable=False)
 flags.define_string(
     "serving_kv_rail",
-    os.environ.get("TRPC_SERVING_KV_RAIL", "auto"),
-    "prefill->decode KV migration rail: auto|local|host "
-    "(auto = local d2d when the plane is up, else host)",
-    validator=lambda v: v in ("auto", "local", "host"))
+    os.environ.get("TRPC_SERVING_KV_RAIL", "local"),
+    "prefill->decode KV migration rail: local (d2d) | host (d2h->h2d); "
+    "either needs the device plane up — a down plane raises, it never "
+    "picks another rail",
+    validator=lambda v: v in ("local", "host"))
 flags.define_string(
     "serving_kv_codec",
     os.environ.get("TRPC_SERVING_KV_CODEC", "none"),
@@ -87,8 +88,9 @@ class KvBlockPlane:
         self.n_blocks = n_blocks or flags.get_flag("serving_kv_blocks")
         self.prefill_device = prefill_device
         if decode_device is None:
-            decode_device = 1 if (tpu_plane.available()
-                                  and tpu_plane.device_count() > 1) else 0
+            # disaggregated when the plane has a second device; with the
+            # plane down every block op raises (h2d), never a host path
+            decode_device = 1 if tpu_plane.device_count() > 1 else 0
         self.decode_device = decode_device
         self.rail = rail or flags.get_flag("serving_kv_rail")
         self.codec = codec or flags.get_flag("serving_kv_codec")
@@ -150,8 +152,7 @@ class KvBlockPlane:
         if table.migrated or self.decode_device == table.device:
             table.migrated = True
             return "none"
-        use_local = (self.rail == "local"
-                     or (self.rail == "auto" and tpu_plane.available()))
+        use_local = self.rail == "local"
         # in-place per-block replacement: a mid-migration failure leaves
         # every charged block reachable from the table, so seq_free still
         # returns all of them

@@ -3,9 +3,11 @@
 Python face of ``native/src/tpu.{h,cc}`` (≙ the reference's RDMA transport,
 ``rdma/rdma_endpoint.h`` + ``rdma/block_pool.cpp``, re-designed for TPU):
 
-* ``init()`` dlopens a PJRT C API plugin (``libtpu.so`` on TPU VMs; the
-  plugin path can be forced with ``$TRPC_PJRT_PLUGIN``) and creates a
-  client.  No JAX involvement — the native core talks PJRT directly.
+* ``init()`` dlopens a PJRT C API plugin — the installed ``libtpu``
+  package's library, or the one ``$TRPC_PJRT_PLUGIN`` names (the fake
+  plugin in tests) — and creates a client of its own.  No JAX involvement
+  — the native core talks PJRT directly; next to JAX's client in one
+  process, libtpu grants this second one.
 * ``h2d()/d2h()`` move bytes host↔HBM through single DMA transfers whose
   completion events store 1 into a butex and wake waiting fibers
   (the butex↔device-event seam the north star names: a fiber awaiting a
@@ -25,8 +27,10 @@ from __future__ import annotations
 
 import ctypes
 import errno
+import glob
+import os
 import threading
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 from brpc_tpu._native import lib
 
@@ -34,13 +38,90 @@ TRANSPORT_STATES = {0: "tcp", 1: "handshaking", 2: "device",
                     3: "fallback_tcp"}
 
 
-def init(plugin_path: Optional[str] = None) -> bool:
-    """Bring up the device plane; returns availability.  Idempotent.
-    On failure the reason is in :func:`error` and callers fall back to
-    TCP explicitly."""
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def _google_pci(dev_dir: str) -> bool:
+    try:
+        with open(os.path.join(dev_dir, "vendor")) as f:
+            return f.read().strip() == _GOOGLE_PCI_VENDOR
+    except OSError:
+        return False
+
+
+def chip_attached() -> bool:
+    """A TPU is attached when its driver's device node exists
+    (/dev/accel0, ...) or a vfio group holds a Google PCI device (TPU v5e
+    is handed to user space through vfio; hosts also load vfio for other
+    devices, so /dev/vfio alone says nothing).  Checked before libtpu is
+    loaded: with no chip, libtpu's client creation blocks for minutes."""
+    if glob.glob("/dev/accel[0-9]*"):
+        return True
+    return any(_google_pci(d)
+               for g in glob.glob("/dev/vfio/[0-9]*")
+               for d in glob.glob(f"/sys/kernel/iommu_groups/"
+                                  f"{os.path.basename(g)}/devices/*"))
+
+
+_resolve_error = ""  # why init() found no plugin to load (Python side)
+
+
+def _libtpu_path() -> str:
+    """The installed libtpu package's library; no package, or no chip for
+    it, is an error (RuntimeError), never a search."""
+    if not chip_attached():
+        raise RuntimeError("no TPU attached (no /dev/accel*, no vfio "
+                           "group with a Google PCI device) and "
+                           "TRPC_PJRT_PLUGIN is unset")
+    try:
+        import libtpu
+    except ImportError as e:
+        raise RuntimeError(
+            "no PJRT plugin: the libtpu package is not installed and "
+            "TRPC_PJRT_PLUGIN is unset") from e
+    return libtpu.get_library_path()
+
+
+def init(plugin_path: Optional[str] = None,
+         timeout_s: Optional[float] = None) -> bool:
+    """Bring up the device plane on ``plugin_path``, else
+    ``$TRPC_PJRT_PLUGIN``, else the installed libtpu; returns
+    availability.  Idempotent.  On failure the reason is in
+    :func:`error` and callers fall back to TCP explicitly.
+
+    ``timeout_s`` bounds the bring-up: client creation can block for
+    minutes on a machine whose chip is broken, and a caller that must
+    fail fast gets ``TimeoutError`` instead (the stuck init thread is
+    abandoned; the process should exit)."""
+    global _resolve_error
     L = lib()
-    L.trpc_tpu_plane_init(plugin_path.encode() if plugin_path else None)
+    if L.trpc_tpu_plane_available():
+        return True
+    path = plugin_path or os.environ.get("TRPC_PJRT_PLUGIN")
+    if not path:
+        try:
+            path = _libtpu_path()
+        except RuntimeError as e:
+            _resolve_error = str(e)
+            return False
+    _resolve_error = ""
+    t = threading.Thread(target=L.trpc_tpu_plane_init,
+                         args=(path.encode(),), name="tpu-plane-init",
+                         daemon=True)
+    t.start()
+    t.join(timeout_s)
+    if t.is_alive():
+        raise TimeoutError(
+            f"device plane init did not finish in {timeout_s}s ({path})")
     return bool(L.trpc_tpu_plane_available())
+
+
+def api_version() -> Dict[str, Tuple[int, int]]:
+    """PJRT C API versions: ``header`` (what the native core was compiled
+    against) and ``plugin`` (the loaded plugin's; (-1, -1) before init)."""
+    out = (ctypes.c_int * 4)()
+    lib().trpc_tpu_plane_api_version(out)
+    return {"header": (out[0], out[1]), "plugin": (out[2], out[3])}
 
 
 def available() -> bool:
@@ -48,7 +129,7 @@ def available() -> bool:
 
 
 def error() -> str:
-    return (lib().trpc_tpu_plane_error() or b"").decode()
+    return _resolve_error or (lib().trpc_tpu_plane_error() or b"").decode()
 
 
 def platform() -> str:

@@ -26,12 +26,13 @@ os.environ.setdefault(
     "XLA_FLAGS",
     (os.environ.get("XLA_FLAGS", "") +
      " --xla_force_host_platform_device_count=8").strip())
-# the device plane wants a PJRT plugin; default to the fake one the
-# native build installs next to the core .so (real TPU VMs override)
-_FAKE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "brpc_tpu", "_native", "libpjrt_fake.so")
-if os.path.exists(_FAKE):
-    os.environ.setdefault("TRPC_PJRT_PLUGIN", _FAKE)
+# on the CPU (JAX_PLATFORMS=cpu) the device plane is the fake PJRT plugin
+# the native build installs next to the core .so; elsewhere it is the
+# installed libtpu, and a down plane is an error
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("TRPC_PJRT_PLUGIN", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "brpc_tpu", "_native", "libpjrt_fake.so"))
 
 import json           # noqa: E402
 import struct         # noqa: E402
@@ -60,8 +61,9 @@ def _pct(xs, p):
 
 
 def main():
-    plane_up = tpu_plane.init()
-    stats0 = tpu_plane.stats() if plane_up else {}
+    if not tpu_plane.init(timeout_s=120):
+        raise SystemExit(f"device plane down: {tpu_plane.error()}")
+    stats0 = tpu_plane.stats()
     mesh = make_mesh({"dp": 2, "tp": 4})
     engine = ServingEngine(cfg=tiny_config(), mesh=mesh,
                            kv=KvBlockPlane(block_bytes=4096, n_blocks=48),
@@ -74,7 +76,7 @@ def main():
     port = server.start("127.0.0.1:0")
     engine.start()
     addr = f"127.0.0.1:{port}"
-    print(f"serving on {addr} (plane={'up' if plane_up else 'DOWN'}, "
+    print(f"serving on {addr} (plane={tpu_plane.platform()}, "
           f"slots=4, blocks=48)")
 
     lock = threading.Lock()
@@ -175,7 +177,7 @@ def main():
     engine.stop()
     engine.assert_drained()
     es = engine.stats()
-    stats1 = tpu_plane.stats() if plane_up else {}
+    stats1 = tpu_plane.stats()
     d2d = stats1.get("d2d_transfers", 0) - stats0.get("d2d_transfers", 0)
     print(f"engine: {json.dumps({k: v for k, v in es.items() if v})}")
     proof = {
@@ -191,7 +193,6 @@ def main():
         "finished": es["finished"],
         "rail_local": es["rail_local"],
         "d2d_delta": d2d,
-        "plane": plane_up,
         "live_buffers_end": stats1.get("live_buffers", 0),
         "balanced": True,  # assert_drained() above would have thrown
         "ttft_ms_p50": round(_pct(out["ttft_ms"], .5), 1),

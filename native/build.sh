@@ -8,8 +8,13 @@
 set -euo pipefail
 cd "$(dirname "$0")"
 mkdir -p build
+# one build at a time per tree (xdist workers' first loads, chip_smoke.py,
+# a developer): a waiter then finds nothing left to build
+exec 9>build/.build.lock
+flock 9
 
 if command -v cmake >/dev/null 2>&1 && command -v ninja >/dev/null 2>&1; then
+  bash forget_foreign_cache.sh build
   cmake -S . -B build -G Ninja -DCMAKE_BUILD_TYPE=Release >/dev/null
   ninja -C build
 else

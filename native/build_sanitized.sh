@@ -37,6 +37,7 @@ run_sweep_if_asked() {
 }
 
 if command -v cmake >/dev/null 2>&1 && command -v ninja >/dev/null 2>&1; then
+  bash forget_foreign_cache.sh "$dir"
   if [[ ! -f "$dir/build.ninja" ]]; then
     cmake -S . -B "$dir" -G Ninja -DSANITIZE="$flavor" >/dev/null || exit 3
   fi

@@ -1004,6 +1004,8 @@ uint64_t trpc_tpu_d2d(uint64_t src, int dst_device) {
 
 uint64_t trpc_tpu_plane_uid() { return tpu_plane_uid(); }
 
+void trpc_tpu_plane_api_version(int out[4]) { tpu_plane_api_version(out); }
+
 // HBM echo service (kind=2): attachments round-trip host->HBM->host.
 int trpc_server_add_hbm_echo(void* s, const char* name) {
   return server_add_service((Server*)s, name, 2, nullptr, nullptr);

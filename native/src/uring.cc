@@ -453,16 +453,18 @@ class RingEngine {
 
   // IORING_REGISTER_PROBE: does this kernel implement IORING_OP_SEND_ZC?
   void ProbeSendZc() {
-    struct {
-      io_uring_probe p;
-      io_uring_probe_op ops[64];
-    } pr;
-    memset(&pr, 0, sizeof(pr));
-    if (sys_io_uring_register(ring_fd_, kRegProbe, &pr, 64) != 0) {
+    // io_uring_probe ends in a flexible array member, so it cannot sit
+    // before another member of a struct (g++ 12 refuses): probe into an
+    // aligned byte buffer sized for 64 ops instead
+    alignas(io_uring_probe) unsigned char
+        buf[sizeof(io_uring_probe) + 64 * sizeof(io_uring_probe_op)];
+    memset(buf, 0, sizeof(buf));
+    io_uring_probe* pr = reinterpret_cast<io_uring_probe*>(buf);
+    if (sys_io_uring_register(ring_fd_, kRegProbe, pr, 64) != 0) {
       return;
     }
-    sendzc_ok_ = pr.p.ops_len > kOpSendZc &&
-                 (pr.ops[kOpSendZc].flags & IO_URING_OP_SUPPORTED) != 0;
+    sendzc_ok_ = pr->ops_len > kOpSendZc &&
+                 (pr->ops[kOpSendZc].flags & IO_URING_OP_SUPPORTED) != 0;
   }
 
   static bool MakeTcpPair(int* a, int* b) {
