@@ -52,86 +52,8 @@ def _scaling_table(cores_avail: int) -> dict:
     return table
 
 
-def _serving_arm(rail: str, codec: str, duration_s: float) -> dict:
-    """One serving-bench arm (ISSUE 14): in-process ServingEngine +
-    Server driven by rpc_press.press_stream.  Runs in its OWN subprocess
-    (the PJRT client and the jit caches are process-global), so env must
-    be staged before the jax import chain.  Under JAX_PLATFORMS=cpu it
-    runs on an 8-device virtual mesh with the fake PJRT plugin; anywhere
-    else on the chip's devices with the installed libtpu."""
-    cpu = os.environ.get("JAX_PLATFORMS") == "cpu"
-    if cpu:
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            (os.environ.get("XLA_FLAGS", "") +
-             " --xla_force_host_platform_device_count=8").strip())
-        os.environ.setdefault("TRPC_PJRT_PLUGIN", os.path.join(
-            os.path.dirname(os.path.abspath(__file__)),
-            "brpc_tpu", "_native", "libpjrt_fake.so"))
-    from brpc_tpu import tpu_plane
-    from brpc_tpu.parallel.mesh import make_mesh
-    from brpc_tpu.rpc.channel import Channel, ChannelOptions
-    from brpc_tpu.rpc.server import Server, ServerOptions
-    from brpc_tpu.serving import ServingEngine
-    from brpc_tpu.serving.engine import tiny_config
-    from brpc_tpu.serving.kv_cache import KvBlockPlane
-    from brpc_tpu.tools.rpc_press import press_stream
-
-    if not tpu_plane.init(timeout_s=120):
-        raise RuntimeError(f"device plane down: {tpu_plane.error()}")
-    engine = ServingEngine(
-        cfg=tiny_config(),
-        mesh=make_mesh({"dp": 2, "tp": 4}) if cpu else None,
-        kv=KvBlockPlane(block_bytes=4096, n_blocks=48,
-                        rail=rail, codec=codec),
-        n_slots=4, max_waiting=4)
-    server = Server(ServerOptions(
-        method_max_concurrency={"LLM.Generate": engine.method_cap}))
-    engine.register(server)
-    port = server.start("127.0.0.1:0")
-    engine.start()
-    addr = f"127.0.0.1:{port}"
-    payload = json.dumps({"prompt_len": 12,
-                          "max_new_tokens": 16}).encode()
-    # warm the jit caches OFF the clock so the timed TTFT/ITL measure
-    # serving, not XLA compilation (admitted-only percentiles stay
-    # honest; BENCH_NOTES.md documents the methodology)
-    ch = Channel(addr, ChannelOptions(timeout_ms=60000, max_retry=0))
-    try:
-        _, st = ch.create_stream("LLM.Generate", payload)
-        while st.read(timeout_s=120) is not None:
-            pass
-        st.destroy()
-    finally:
-        ch.close()
-    res = press_stream(addr, "LLM.Generate", payload,
-                       concurrency=6, duration_s=duration_s)
-    engine.stop()
-    engine.assert_drained()          # raises on a block leak
-    es = engine.stats()
-    server.destroy()
-    return {
-        "metric": "serving_bench", "rail": rail, "codec": codec,
-        "plane": tpu_plane.platform(), "duration_s": round(res.wall_s, 2),
-        "streams": res.streams, "completed": res.completed,
-        "shed": res.shed, "resets": res.resets, "errors": res.errors,
-        "tokens": res.tokens,
-        "tokens_per_s": round(res.tokens_per_s, 1),
-        "ttft_p50_us": res._pct(res.ttft_us, .5),
-        "ttft_p99_us": res._pct(res.ttft_us, .99),
-        "gap_p50_us": res._pct(res.gap_us, .5),
-        "gap_p99_us": res._pct(res.gap_us, .99),
-        "gap_p999_us": res._pct(res.gap_us, .999),
-        "rail_local": es["rail_local"], "rail_host": es["rail_host"],
-        "kv_codec_bytes": es["kv_codec_bytes"],
-        "preemptions": es["preemptions"],
-        "balanced": True,
-    }
-
-
 def main() -> int:
-    # the echo bench never imports JAX; the --serving parent stays off it
-    # too (its arm subprocesses own the chip, one at a time)
+    # the echo bench never imports JAX
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import ctypes
 
@@ -174,49 +96,7 @@ def main() -> int:
     ap.add_argument("--codec-skip-allreduce", action="store_true",
                     help="with --codec-ab: skip the (slow, JAX) "
                          "allreduce legs and sweep attachments only")
-    ap.add_argument("--serving", action="store_true",
-                    help="LLM-serving bench (ISSUE 14): tokens/s + "
-                         "admitted-only TTFT/ITL from rpc_press "
-                         "--stream against the continuous-batching "
-                         "engine, with the KV-migration rail/codec A/B "
-                         "(local-rail headline, then host-rail "
-                         "none/bf16/int8; one subprocess per arm: the "
-                         "PJRT client is process-global)")
-    ap.add_argument("--serving-arm", default="",
-                    help="internal: run ONE serving arm as "
-                         "'rail,codec' and print its JSON line")
     args = ap.parse_args()
-
-    if args.serving_arm:
-        rail, codec = args.serving_arm.split(",")
-        print(json.dumps(_serving_arm(rail, codec,
-                                      4.0 if args.brief else 8.0)))
-        return 0
-
-    if args.serving:
-        me = os.path.abspath(__file__)
-        table = {}
-        for rail, codec in (("local", "none"), ("host", "none"),
-                            ("host", "bf16"), ("host", "int8")):
-            key = f"{rail}/{codec}"
-            try:
-                cmd = [sys.executable, me, "--serving-arm",
-                       f"{rail},{codec}"]
-                if args.brief:
-                    cmd.append("--brief")
-                r = subprocess.run(cmd, capture_output=True, text=True,
-                                   timeout=600)
-                if r.returncode != 0:
-                    raise RuntimeError(f"arm rc={r.returncode}: "
-                                       f"{r.stderr[-300:]}")
-                table[key] = json.loads(r.stdout.strip().splitlines()[-1])
-            except Exception as e:  # noqa: BLE001 — arm -> error cell
-                table[key] = {"error": str(e)}
-        head = table.get("local/none", {})
-        print(json.dumps({"metric": "serving_ab",
-                          "value": head.get("tokens_per_s"),
-                          "unit": "tokens/s", "table": table}))
-        return 0
 
     if args.codec_ab:
         me = os.path.abspath(__file__)

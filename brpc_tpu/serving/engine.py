@@ -18,6 +18,18 @@ RPC cancel, client socket death — funnels through `_end()`, which frees
 the sequence's KV blocks exactly once; `assert_drained()` +
 `tpu_plane.stats()["live_buffers"]` is the accounting proof the suite
 pins.
+
+The decode loop times its own phases (`PHASES`): each is a leaf call of
+the loop thread, so no nanosecond is counted twice, and each adds to a
+`<phase>_ns` counter in `stats()` and, where it has a span, opens
+`jax.profiler.TraceAnnotation("serving/<phase>")` (a no-op unless a
+profiler runs).  Spans that hold phases but count nothing:
+
+    serving/admit   one admission (seq, slot, plen): prefill, kv_*, the
+                    first token's sync and emit
+    serving/step    one decode step (n, ctx): dispatch, sync, emit
+    serving/emit    the step's tokens handed to their streams:
+                    emit_write (no span of its own), kv_grow, retire
 """
 
 from __future__ import annotations
@@ -26,12 +38,16 @@ import json
 import os
 import struct
 import threading
+import time
+import weakref
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from brpc_tpu.metrics import bvar
 from brpc_tpu.models import decode as D
 from brpc_tpu.models.transformer import ModelConfig
 from brpc_tpu.models import transformer
@@ -53,6 +69,55 @@ flags.define_int32(
     "evicted (shed, not queued — engine.py)")
 
 TOKEN_FMT = "<I"  # one generated token = 4 LE bytes on the stream
+
+# the decode loop's leaf phases, each a `<phase>_ns` counter in stats():
+#   idle        waiting for work (sched.work.wait)
+#   prefill     the prefill program dispatched
+#   kv_d2h      prefill K/V to host bytes (decode.kv_to_bytes), which
+#               waits for the prefill program on the device
+#   kv_alloc    the bytes into pool blocks (KvBlockPlane.seq_alloc)
+#   kv_migrate  the blocks to the decode device (seq_migrate)
+#   kv_fetch    the migrated blocks back to host bytes (seq_fetch)
+#   kv_install  bytes to arrays and decode.install dispatched
+#   dispatch    a decode step's inputs built and its program dispatched
+#   sync        argmax of the logits copied to the host (every step and
+#               every admission's first token)
+#   emit_write  one token written to its stream (no span)
+#   kv_grow     one more KV block charged (KvBlockPlane.seq_grow)
+#   retire      a sequence retired (_end)
+PHASES = ("idle", "prefill", "kv_d2h", "kv_alloc", "kv_migrate",
+          "kv_fetch", "kv_install", "dispatch", "sync", "emit_write",
+          "kv_grow", "retire")
+
+_vars_engine = None   # weakref to the engine the serving_* bvars read
+
+
+def _serving_var(key: str):
+    engine = _vars_engine() if _vars_engine is not None else None
+    return engine.stats().get(key, 0) if engine is not None else 0
+
+
+class _Phase:
+    """Times one leaf phase of the decode loop into `ns[name]` and, with
+    `span`, names it `serving/<name>` in a profiler trace."""
+
+    __slots__ = ("ns", "name", "ann", "t0")
+
+    def __init__(self, ns: Dict[str, int], name: str, span: bool = True,
+                 **stats):
+        self.ns, self.name = ns, name
+        self.ann = TraceAnnotation("serving/" + name, **stats) \
+            if span else None
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = time.monotonic_ns()
+
+    def __exit__(self, *exc):
+        self.ns[self.name] += time.monotonic_ns() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
 
 
 def tiny_config(**over) -> ModelConfig:
@@ -101,9 +166,12 @@ class ServingEngine:
         # counters
         self.tokens_out = 0
         self.prefills = 0
+        self.prefill_compiles = 0
         self.steps = 0
         self.preemptions = 0
         self.rails = {"local": 0, "host": 0, "none": 0}
+        self.phase_ns: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        self._loop_t = [0, 0]    # the loop thread's start, end (ns)
 
     # -- ingress (handler threads) ------------------------------------------
 
@@ -116,7 +184,15 @@ class ServingEngine:
         return self.n_slots + self.sched.max_waiting + 1
 
     def register(self, server, method: str = "LLM.Generate") -> None:
+        """Serve `method` on `server`, and expose `stats()` to operators
+        as `serving_<key>` bvars, which read the engine registered last
+        (each name is exposed once per process)."""
+        global _vars_engine
         server.add_service(method, self.handle)
+        _vars_engine = weakref.ref(self)
+        for key in self.stats():
+            bvar.PassiveStatus(lambda k=key: _serving_var(k),
+                               f"serving_{key}")
 
     def handle(self, cntl, req: bytes):
         """The Generate handler: parse, accept the stream, submit."""
@@ -180,9 +256,11 @@ class ServingEngine:
         self.kv.assert_balanced()
 
     def stats(self) -> Dict[str, int]:
+        t_start, t_end = self._loop_t
         d = {
             "tokens_out": self.tokens_out,
             "prefills": self.prefills,
+            "prefill_compiles": self.prefill_compiles,
             "steps": self.steps,
             "preemptions": self.preemptions,
             "rail_local": self.rails["local"],
@@ -197,36 +275,49 @@ class ServingEngine:
             "canceled": self.sched.canceled,
             "waiting": self.sched.waiting_depth(),
             "running": len(self.sched.running()),
+            "queue_wait_ns": self.sched.queue_wait_ns,
+            "loop_ns": ((t_end or time.monotonic_ns()) - t_start
+                        if t_start else 0),
         }
+        d.update({f"{k}_ns": v for k, v in self.phase_ns.items()})
         d.update(self.kv.stats())
         return d
+
+    def _phase(self, name: str, span: bool = True, **stats) -> _Phase:
+        return _Phase(self.phase_ns, name, span, **stats)
 
     # -- decode loop --------------------------------------------------------
 
     def _loop(self) -> None:
-        while not self._stop.is_set():
-            did = False
-            # prefill/decode interleave: at most ONE admission per step,
-            # so running streams keep their inter-token cadence while
-            # the waiting room drains
-            seq = self.sched.pop_admittable()
-            if seq is not None:
-                self._admit(seq)
-                did = True
-            batch = [s for s in self.sched.running()
-                     if s.state == S.RUNNING]
-            if batch:
-                self._decode_batch(batch)
-                did = True
-            if not did:
-                self.sched.work.wait(0.02)
-                self.sched.work.clear()
+        self._loop_t = [time.monotonic_ns(), 0]
+        try:
+            while not self._stop.is_set():
+                did = False
+                # prefill/decode interleave: at most ONE admission per
+                # step, so running streams keep their inter-token cadence
+                # while the waiting room drains
+                seq = self.sched.pop_admittable()
+                if seq is not None:
+                    self._admit(seq)
+                    did = True
+                batch = [s for s in self.sched.running()
+                         if s.state == S.RUNNING]
+                if batch:
+                    self._decode_batch(batch)
+                    did = True
+                if not did:
+                    with self._phase("idle"):
+                        self.sched.work.wait(0.02)
+                    self.sched.work.clear()
+        finally:
+            self._loop_t[1] = time.monotonic_ns()
 
     def _prefill_fn(self, plen: int):
         fn = self._jprefill.get(plen)
         if fn is None:
             fn = jax.jit(lambda p, t: D.prefill(p, t, self.cfg, self.mesh))
             self._jprefill[plen] = fn
+            self.prefill_compiles += 1
         return fn
 
     def _admit(self, seq: S.Sequence) -> None:
@@ -236,56 +327,75 @@ class ServingEngine:
             self._end(seq, S.CANCELED, "canceled before prefill")
             return
         plen = seq.prompt_len
-        toks = jnp.asarray([seq.prompt], jnp.int32)
-        logits, k, v = self._prefill_fn(plen)(self.params, toks)
-        kvb = D.kv_to_bytes(k[:, 0], v[:, 0])
-        try:
-            self.kv.seq_alloc(seq.seq_id, kvb)
-            rail = self.kv.seq_migrate(seq.seq_id)
-        except Exception as e:  # PoolExhausted or a plane fault
-            self.kv.seq_free(seq.seq_id)
-            self._end(seq, S.EVICTED, f"prefill shed: {e}",
-                      rst_code=errors.ELIMIT)
-            return
-        self.rails[rail] = self.rails.get(rail, 0) + 1
-        k2, v2 = D.kv_from_bytes(self.kv.seq_fetch(seq.seq_id),
-                                 self.cfg, plen)
-        self.cache = D.install(self.cache, seq.slot, k2, v2, plen)
-        self.prefills += 1
-        first = int(np.asarray(jnp.argmax(logits[0])))
-        if self._emit(seq, first) and seq.generated >= seq.max_new_tokens:
-            self._end(seq, S.FINISHED, "max_new_tokens")
+        with TraceAnnotation("serving/admit", seq=seq.seq_id, slot=seq.slot,
+                             plen=plen):
+            # no wait for the prefill here: kv_to_bytes's copy waits for
+            # it, and a wait of its own cost 1.5-3 ms an admission (GPT-2
+            # small, 16 slots, one v5e)
+            with self._phase("prefill"):
+                toks = jnp.asarray([seq.prompt], jnp.int32)
+                logits, k, v = self._prefill_fn(plen)(self.params, toks)
+            with self._phase("kv_d2h"):
+                kvb = D.kv_to_bytes(k[:, 0], v[:, 0])
+            try:
+                with self._phase("kv_alloc"):
+                    self.kv.seq_alloc(seq.seq_id, kvb)
+                with self._phase("kv_migrate"):
+                    rail = self.kv.seq_migrate(seq.seq_id)
+            except Exception as e:  # PoolExhausted or a plane fault
+                self.kv.seq_free(seq.seq_id)
+                self._end(seq, S.EVICTED, f"prefill shed: {e}",
+                          rst_code=errors.ELIMIT)
+                return
+            self.rails[rail] = self.rails.get(rail, 0) + 1
+            with self._phase("kv_fetch"):
+                kvb = self.kv.seq_fetch(seq.seq_id)
+            with self._phase("kv_install"):
+                k2, v2 = D.kv_from_bytes(kvb, self.cfg, plen)
+                self.cache = D.install(self.cache, seq.slot, k2, v2, plen)
+            self.prefills += 1
+            with self._phase("sync"):
+                first = int(np.asarray(jnp.argmax(logits[0])))
+            if self._emit(seq, first) and \
+                    seq.generated >= seq.max_new_tokens:
+                self._end(seq, S.FINISHED, "max_new_tokens")
 
     def _decode_batch(self, batch) -> None:
-        tokens = np.zeros((self.n_slots,), np.int32)
-        active = np.zeros((self.n_slots,), bool)
-        for s in batch:
-            tokens[s.slot] = s.last_token
-            active[s.slot] = True
-        logits, self.cache = self._jstep(self.params, self.cache,
-                                         jnp.asarray(tokens),
-                                         jnp.asarray(active))
-        self.steps += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for s in batch:
-            if s.state != S.RUNNING:
-                continue  # evicted by a preemption earlier in this pass
-            if s.cntl is not None and s.cntl.is_canceled():
-                # the RPC cancel already RST the accepted stream
-                # natively; our job is only the block accounting
-                self._end(s, S.CANCELED, "rpc canceled", rst=False)
-                continue
-            if self._emit(s, int(nxt[s.slot])) and \
-                    s.generated >= s.max_new_tokens:
-                self._end(s, S.FINISHED, "max_new_tokens")
+        with TraceAnnotation("serving/step", n=len(batch),
+                             ctx=sum(s.total_len for s in batch)):
+            with self._phase("dispatch"):
+                tokens = np.zeros((self.n_slots,), np.int32)
+                active = np.zeros((self.n_slots,), bool)
+                for s in batch:
+                    tokens[s.slot] = s.last_token
+                    active[s.slot] = True
+                logits, self.cache = self._jstep(self.params, self.cache,
+                                                 jnp.asarray(tokens),
+                                                 jnp.asarray(active))
+            self.steps += 1
+            with self._phase("sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with TraceAnnotation("serving/emit"):
+                for s in batch:
+                    if s.state != S.RUNNING:
+                        continue  # evicted by a preemption earlier
+                    if s.cntl is not None and s.cntl.is_canceled():
+                        # the RPC cancel already RST the accepted stream
+                        # natively; our job is only the block accounting
+                        self._end(s, S.CANCELED, "rpc canceled", rst=False)
+                        continue
+                    if self._emit(s, int(nxt[s.slot])) and \
+                            s.generated >= s.max_new_tokens:
+                        self._end(s, S.FINISHED, "max_new_tokens")
 
     def _emit(self, seq: S.Sequence, token: int) -> bool:
         """Send one token and keep the block charge covering the
         sequence's total length; False when the sequence ended here."""
         try:
-            seq.stream.write(struct.pack(TOKEN_FMT, token),
-                             timeout_s=flags.get_flag(
-                                 "serving_write_timeout_ms") / 1e3)
+            with self._phase("emit_write", span=False):
+                seq.stream.write(struct.pack(TOKEN_FMT, token),
+                                 timeout_s=flags.get_flag(
+                                     "serving_write_timeout_ms") / 1e3)
         except StreamReset:
             self._end(seq, S.CANCELED, "stream reset by peer", rst=False)
             return False
@@ -311,7 +421,8 @@ class ServingEngine:
             seq.total_len * self.sched.bytes_per_token)
         while self.kv.seq_blocks(seq.seq_id) < needed:
             try:
-                self.kv.seq_grow(seq.seq_id)
+                with self._phase("kv_grow"):
+                    self.kv.seq_grow(seq.seq_id)
             except Exception:  # PoolExhausted
                 victim = self.sched.preempt_victim()
                 if victim is None:
@@ -327,14 +438,15 @@ class ServingEngine:
              rst: bool = True, rst_code: int = errors.ECANCELED) -> None:
         """The single retirement path: slot back, blocks freed exactly
         once, stream closed (clean for FINISHED, RST otherwise)."""
-        self.sched.release(seq, state, reason)
-        if seq.slot >= 0:
-            self.cache = D.reset_slot(self.cache, seq.slot)
-        self.kv.seq_free(seq.seq_id)
-        try:
-            if state == S.FINISHED:
-                seq.stream.close()
-            elif rst:
-                seq.stream.rst(rst_code)
-        except Exception:
-            pass  # the peer may already be gone; accounting is done
+        with self._phase("retire"):
+            self.sched.release(seq, state, reason)
+            if seq.slot >= 0:
+                self.cache = D.reset_slot(self.cache, seq.slot)
+            self.kv.seq_free(seq.seq_id)
+            try:
+                if state == S.FINISHED:
+                    seq.stream.close()
+                elif rst:
+                    seq.stream.rst(rst_code)
+            except Exception:
+                pass  # the peer may already be gone; accounting is done

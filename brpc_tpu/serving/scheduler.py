@@ -93,6 +93,7 @@ class Scheduler:
         self.finished = 0
         self.evicted = 0
         self.canceled = 0
+        self.queue_wait_ns = 0     # sum of admit_ns - submit_ns
 
     # -- admission (handler threads) ----------------------------------------
 
@@ -140,6 +141,7 @@ class Scheduler:
             seq.admit_ns = time.monotonic_ns()
             self._slots[slot] = seq
             self.admitted += 1
+            self.queue_wait_ns += seq.admit_ns - seq.submit_ns
             return seq
 
     def running(self) -> List[Sequence]:

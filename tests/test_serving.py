@@ -383,3 +383,192 @@ def test_budget_shed_preemption_and_host_codec():
     r = _run(PREEMPT_CODE, env_extra=SERVE_ENV)
     assert r.returncode == 0 and "PREEMPT-OK" in r.stdout, \
         f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+
+
+# ---------------------------------------------------------------------------
+# the decode loop's phase counters and spans
+# ---------------------------------------------------------------------------
+
+
+def test_queue_wait_sums_admission_waits():
+    """queue_wait_ns is the sum of admit_ns - submit_ns over the admitted
+    sequences, and a sequence still waiting adds nothing."""
+    from brpc_tpu.serving.kv_cache import KvBlockPlane
+    from brpc_tpu.serving import scheduler as S
+
+    sched = S.Scheduler(n_slots=2, kv=KvBlockPlane(block_bytes=4096,
+                                                   n_blocks=8),
+                        bytes_per_token=1024, max_waiting=4)
+    seqs = [S.Sequence(seq_id=i, prompt=[1] * 4, max_new_tokens=4)
+            for i in range(3)]
+    for s in seqs:
+        sched.submit(s)
+    admitted = [sched.pop_admittable(), sched.pop_admittable()]
+    assert sched.pop_admittable() is None          # both slots taken
+    assert admitted == seqs[:2]
+    assert sched.queue_wait_ns == sum(s.admit_ns - s.submit_ns
+                                      for s in admitted)
+    assert sched.queue_wait_ns > 0
+
+
+PHASES_CODE = r"""
+import glob, json, os, tempfile, threading, urllib.request
+import jax
+from jax.profiler import ProfileData
+from brpc_tpu import tpu_plane
+from brpc_tpu.metrics import bvar
+from brpc_tpu.rpc.channel import Channel, ChannelOptions
+from brpc_tpu.rpc.server import Server
+from brpc_tpu.serving import ServingEngine
+from brpc_tpu.serving.engine import tiny_config
+from brpc_tpu.serving.kv_cache import KvBlockPlane
+
+assert tpu_plane.init(), tpu_plane.error()
+engine = ServingEngine(cfg=tiny_config(),
+                       kv=KvBlockPlane(block_bytes=4096, n_blocks=48),
+                       n_slots=2, max_waiting=6)
+server = Server()
+engine.register(server)
+addr = f"127.0.0.1:{server.start('127.0.0.1:0')}"
+engine.start()
+
+
+def generate(max_new):
+    ch = Channel(addr, ChannelOptions(timeout_ms=60000, max_retry=0))
+    try:
+        _, st = ch.create_stream("LLM.Generate", json.dumps(
+            {"prompt_len": 12, "max_new_tokens": max_new}).encode())
+        n = 0
+        while st.read(timeout_s=120) is not None:
+            n += 1
+        st.destroy()
+        return n
+    finally:
+        ch.close()
+
+
+assert generate(4) == 4                      # compiles, outside the trace
+trace_dir = tempfile.mkdtemp()
+jax.profiler.start_trace(trace_dir)
+got = []
+def client():
+    for _ in range(2):
+        got.append(generate(16))
+ts = [threading.Thread(target=client) for _ in range(3)]
+for t in ts:
+    t.start()
+for t in ts:
+    t.join(120)
+jax.profiler.stop_trace()
+assert got == [16] * 6, got
+portal = urllib.request.urlopen(
+    f"http://{addr}/vars?filter=serving_", timeout=10).read().decode()
+engine.stop()
+engine.assert_drained()
+stats = engine.stats()
+server.destroy()
+
+# a second engine: the serving_* vars follow the one registered last
+engine2 = ServingEngine(cfg=tiny_config(),
+                        kv=KvBlockPlane(block_bytes=4096, n_blocks=8),
+                        n_slots=1, max_waiting=1)
+server2 = Server()
+engine2.register(server2)
+second = bvar.describe_exposed("serving_tokens_out")
+server2.destroy()
+
+spans = []
+path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                 recursive=True)[0]
+for pl in ProfileData.from_file(path).planes:
+    for ln in pl.lines:
+        for ev in ln.events:
+            if ev.name.startswith("serving/"):
+                spans.append([f"{pl.name}|{ln.name}", ev.name[8:],
+                              int(ev.start_ns), int(ev.duration_ns),
+                              {k: str(v) for k, v in ev.stats}])
+print("PHASES " + json.dumps({"stats": stats, "spans": spans,
+                              "portal": portal, "second": second}))
+"""
+
+LEAF_SPANS = ("idle", "prefill", "kv_d2h", "kv_alloc", "kv_migrate",
+              "kv_fetch", "kv_install", "dispatch", "sync", "kv_grow",
+              "retire")
+
+
+@pytest.fixture(scope="module")
+def phases_run():
+    """The tiny engine serving six requests on two slots (three clients,
+    so requests queue) under a profiler trace."""
+    _need_fake()
+    r = _run(PHASES_CODE, env_extra=SERVE_ENV)
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("PHASES ")]
+    assert r.returncode == 0 and line, \
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    return __import__("json").loads(line[-1][len("PHASES "):])
+
+
+def test_every_phase_counter_moves(phases_run):
+    from brpc_tpu.serving.engine import PHASES
+    st = phases_run["stats"]
+    for ph in PHASES:
+        assert st[f"{ph}_ns"] > 0, (ph, st)
+    assert st["queue_wait_ns"] > 0 and st["loop_ns"] > 0, st
+    assert st["prefill_compiles"] == 1, st      # one prompt length
+    assert st["kv_grown_blocks"] > 0, st
+
+
+def test_phase_counters_cover_the_loop(phases_run):
+    """The leaf phases never overlap, so they sum to at most the loop's
+    time, and the loop does little outside them."""
+    from brpc_tpu.serving.engine import PHASES
+    st = phases_run["stats"]
+    leaves = sum(st[f"{ph}_ns"] for ph in PHASES)
+    assert leaves <= st["loop_ns"], st
+    assert st["loop_ns"] - leaves <= 0.05 * st["loop_ns"], st
+
+
+def test_phase_spans_nest_in_the_trace(phases_run):
+    """serving/step holds dispatch, sync and emit; serving/admit holds
+    prefill and the kv_* hops; the counted spans are disjoint; the spans
+    carry their stats."""
+    spans = phases_run["spans"]
+    lines = {s[0] for s in spans if s[1] == "step"}
+    assert len(lines) == 1, lines                 # the decode-loop thread
+    spans = [s for s in spans if s[0] in lines]
+
+    def of(name):
+        return [(s[2], s[2] + s[3], s[4]) for s in spans if s[1] == name]
+
+    def inside(child, parents):
+        return all(any(p0 <= c0 and c1 <= p1 for p0, p1, _ in parents)
+                   for c0, c1, _ in of(child))
+
+    steps, admits = of("step"), of("admit")
+    assert len(steps) >= 16 and len(admits) == 6, (len(steps), len(admits))
+    for child in ("dispatch", "emit"):
+        assert of(child) and inside(child, steps), child
+    for child in ("prefill", "kv_d2h", "kv_alloc", "kv_migrate",
+                  "kv_fetch", "kv_install"):
+        assert len(of(child)) == 6 and inside(child, admits), child
+    assert len(of("sync")) == len(steps) + len(admits)
+    assert of("kv_grow") and inside("kv_grow", steps + admits)
+    assert all(int(st["n"]) >= 1 and int(st["ctx"]) >= 12
+               for _, _, st in steps)
+    assert sorted(int(st["plen"]) for _, _, st in admits) == [12] * 6
+    assert {int(st["slot"]) for _, _, st in admits} <= {0, 1}
+    leaves = sorted((s[2], s[2] + s[3], s[1]) for s in spans
+                    if s[1] in LEAF_SPANS)
+    for a, b in zip(leaves, leaves[1:]):
+        assert a[1] <= b[0], (a, b)
+
+
+def test_serving_stats_on_vars(phases_run):
+    """register() exposes stats() as serving_<key> bvars on /vars; a
+    later engine's register() moves them to that engine."""
+    portal = dict(ln.split(" : ", 1) for ln in
+                  phases_run["portal"].strip().splitlines())
+    assert int(portal["serving_tokens_out"]) == \
+        phases_run["stats"]["tokens_out"] == 4 + 6 * 16
+    assert "serving_emit_write_ns" in portal
+    assert phases_run["second"] == "0"
