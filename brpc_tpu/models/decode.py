@@ -13,6 +13,11 @@ batcher can interleave them — here the seam matters because prefill K/V
 migrates prefill-device → decode-device through the tpu_plane block rail
 (serving/kv_cache.py) before `install()` makes it visible to the step.
 
+Both programs read a serving tree (`serving_params()`): the matmul
+weights and a tied LM head `head` already in cfg.dtype, cast once when
+the engine takes its parameters, never inside a program.  The embedding
+table the gather reads, positions and LayerNorm stay float32.
+
 Dense-only (cfg.n_experts == 0): the serving path drives the dense
 transformer; MoE decode is an optimization path, not a serving
 requirement.
@@ -39,11 +44,56 @@ def _check_dense(cfg: ModelConfig) -> None:
         raise ValueError("decode path is dense-only (cfg.n_experts == 0)")
 
 
+# the block weights that enter a matmul; with `head` they are what
+# serving_params() casts to cfg.dtype
+MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w1", "w2")
+
+
 def kv_bytes_per_token(cfg: ModelConfig) -> int:
     """Host-wire bytes one token position contributes to a sequence's
     K/V: 2 (k+v) x L x H x head_dim f32 values."""
     return 2 * cfg.n_layers * cfg.n_heads * cfg.head_dim * \
         KV_WIRE_DTYPE().itemsize
+
+
+# ---------------------------------------------------------------------------
+# the serving tree
+
+
+def serving_params(params: Dict, cfg: ModelConfig) -> Dict:
+    """A transformer.init()-layout tree -> the tree prefill() and
+    decode_step() read: the block matmul weights in cfg.dtype, plus `head`,
+    the tied LM head, = embed in cfg.dtype.  `embed` (the gather), `pos`
+    and the LayerNorm leaves stay as they are, since they enter float32
+    math.  A cast keeps its leaf's sharding (`head` takes embed's), so
+    place the tree before this; with cfg.dtype float32 every leaf is the
+    one it was."""
+    _check_dense(cfg)
+    blocks = dict(params["blocks"])
+    for name in MATMUL_WEIGHTS:
+        blocks[name] = blocks[name].astype(cfg.dtype)
+    out = dict(params, blocks=blocks)
+    out["head"] = params["embed"].astype(cfg.dtype)
+    return out
+
+
+def param_bytes(params: Dict) -> int:
+    """Bytes of a parameter tree (shapes will do), each array once."""
+    leaves = {id(x): x for x in jax.tree.leaves(params)}
+    return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+               for x in leaves.values())
+
+
+def _check_serving(params: Dict, cfg: ModelConfig) -> None:
+    """At trace time: every matmul weight is in cfg.dtype, so no program
+    casts a weight (or silently runs its matmuls in float32)."""
+    want = jnp.dtype(cfg.dtype)
+    got = {f"blocks.{n}": params["blocks"][n].dtype for n in MATMUL_WEIGHTS}
+    got["head"] = params["head"].dtype if "head" in params else None
+    bad = {k: str(v) for k, v in got.items() if v != want}
+    if bad:
+        raise ValueError(f"not a serving tree for {want} (use "
+                         f"decode.serving_params): {bad}")
 
 
 # ---------------------------------------------------------------------------
@@ -104,8 +154,10 @@ def prefill(params: Dict, tokens, cfg: ModelConfig,
 
     Same math as transformer.apply()'s gather branch, but inference-mode
     (no checkpoint) and the per-layer K/V survives as the migration
-    payload instead of dying with the activations."""
+    payload instead of dying with the activations.  `params` is a
+    serving_params() tree."""
     _check_dense(cfg)
+    _check_serving(params, cfg)
     B, S = tokens.shape
     x = params["embed"][tokens] + params["pos"][:S][None]
     x = _cs(x, mesh, P("dp", "sp", None))
@@ -115,9 +167,9 @@ def prefill(params: Dict, tokens, cfg: ModelConfig,
     for i in range(cfg.n_layers):
         h = _layernorm(x, lp["ln1_g"][i], lp["ln1_b"][i])
         hc = h.astype(cfg.dtype)
-        q = jnp.einsum("bsd,dhk->bshk", hc, lp["wq"][i].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", hc, lp["wk"][i].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", hc, lp["wv"][i].astype(cfg.dtype))
+        q = jnp.einsum("bsd,dhk->bshk", hc, lp["wq"][i])
+        k = jnp.einsum("bsd,dhk->bshk", hc, lp["wk"][i])
+        v = jnp.einsum("bsd,dhk->bshk", hc, lp["wv"][i])
         ks.append(k)
         vs.append(v)
         q = _cs(q, mesh, P("dp", "sp", "tp", None))
@@ -129,19 +181,16 @@ def prefill(params: Dict, tokens, cfg: ModelConfig,
                            -1e30)
         w = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         o = jnp.einsum("bhst,bthk->bshk", w, v)
-        x = x + jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
-                           lp["wo"][i].astype(cfg.dtype))
+        x = x + jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype), lp["wo"][i])
         h = _layernorm(x, lp["ln2_g"][i], lp["ln2_b"][i])
-        hf = jnp.einsum("bsd,df->bsf", h.astype(cfg.dtype),
-                        lp["w1"][i].astype(cfg.dtype))
+        hf = jnp.einsum("bsd,df->bsf", h.astype(cfg.dtype), lp["w1"][i])
         hf = jax.nn.gelu(hf)
-        x = x + jnp.einsum("bsf,fd->bsd", hf, lp["w2"][i].astype(cfg.dtype))
+        x = x + jnp.einsum("bsf,fd->bsd", hf, lp["w2"][i])
         x = _cs(x, mesh, P("dp", "sp", None))
 
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     last = x[:, -1]
-    logits = jnp.einsum("bd,vd->bv", last.astype(cfg.dtype),
-                        params["embed"].astype(cfg.dtype))
+    logits = jnp.einsum("bd,vd->bv", last.astype(cfg.dtype), params["head"])
     return (logits.astype(jnp.float32),
             jnp.stack(ks), jnp.stack(vs))
 
@@ -157,8 +206,10 @@ def decode_step(params: Dict, cache: Dict, tokens, active,
 
     Inactive slots still flow through the math (static shapes) but their
     `pos` does not advance and their scatter lands on a clamped index the
-    next install overwrites — the batcher just ignores their logits."""
+    next install overwrites — the batcher just ignores their logits.
+    `params` is a serving_params() tree."""
     _check_dense(cfg)
+    _check_serving(params, cfg)
     k_cache, v_cache, pos = cache["k"], cache["v"], cache["pos"]
     N = tokens.shape[0]
     S = k_cache.shape[2]
@@ -172,9 +223,9 @@ def decode_step(params: Dict, cache: Dict, tokens, active,
     for i in range(cfg.n_layers):
         h = _layernorm(x, lp["ln1_g"][i], lp["ln1_b"][i])
         hc = h.astype(cfg.dtype)
-        q = jnp.einsum("nd,dhk->nhk", hc, lp["wq"][i].astype(cfg.dtype))
-        k_new = jnp.einsum("nd,dhk->nhk", hc, lp["wk"][i].astype(cfg.dtype))
-        v_new = jnp.einsum("nd,dhk->nhk", hc, lp["wv"][i].astype(cfg.dtype))
+        q = jnp.einsum("nd,dhk->nhk", hc, lp["wq"][i])
+        k_new = jnp.einsum("nd,dhk->nhk", hc, lp["wk"][i])
+        v_new = jnp.einsum("nd,dhk->nhk", hc, lp["wv"][i])
         k_cache = k_cache.at[i, rows, p].set(k_new)
         v_cache = v_cache.at[i, rows, p].set(v_new)
         q = _cs(q, mesh, P("dp", "tp", None))
@@ -184,18 +235,15 @@ def decode_step(params: Dict, cache: Dict, tokens, active,
                            -1e30)
         w = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         o = jnp.einsum("nhs,nshk->nhk", w, v_cache[i])
-        x = x + jnp.einsum("nhk,hkd->nd", o.astype(cfg.dtype),
-                           lp["wo"][i].astype(cfg.dtype))
+        x = x + jnp.einsum("nhk,hkd->nd", o.astype(cfg.dtype), lp["wo"][i])
         h = _layernorm(x, lp["ln2_g"][i], lp["ln2_b"][i])
-        hf = jnp.einsum("nd,df->nf", h.astype(cfg.dtype),
-                        lp["w1"][i].astype(cfg.dtype))
+        hf = jnp.einsum("nd,df->nf", h.astype(cfg.dtype), lp["w1"][i])
         hf = jax.nn.gelu(hf)
-        x = x + jnp.einsum("nf,fd->nd", hf, lp["w2"][i].astype(cfg.dtype))
+        x = x + jnp.einsum("nf,fd->nd", hf, lp["w2"][i])
         x = _cs(x, mesh, P("dp", None))
 
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    logits = jnp.einsum("nd,vd->nv", x.astype(cfg.dtype),
-                        params["embed"].astype(cfg.dtype))
+    logits = jnp.einsum("nd,vd->nv", x.astype(cfg.dtype), params["head"])
     new_cache = {
         "k": k_cache,
         "v": v_cache,

@@ -142,11 +142,14 @@ class ServingEngine:
                  max_waiting: Optional[int] = None, seed: int = 0):
         self.cfg = cfg or tiny_config()
         self.mesh = mesh
-        self.params = params if params is not None else transformer.init(
-            jax.random.PRNGKey(seed), self.cfg)
+        if params is None:
+            params = transformer.init(jax.random.PRNGKey(seed), self.cfg)
         if mesh is not None:
-            self.params = transformer.place_params(self.params, self.cfg,
-                                                   mesh)
+            params = transformer.place_params(params, self.cfg, mesh)
+        # the programs' weights in cfg.dtype from here on; the float32
+        # tree is not kept
+        self.params = D.serving_params(params, self.cfg)
+        self.param_bytes = D.param_bytes(self.params)
         self.n_slots = n_slots or flags.get_flag("serving_slots")
         self.max_len = max_len or self.cfg.max_seq
         self.kv = kv or KvBlockPlane()
@@ -276,6 +279,7 @@ class ServingEngine:
             "waiting": self.sched.waiting_depth(),
             "running": len(self.sched.running()),
             "queue_wait_ns": self.sched.queue_wait_ns,
+            "param_bytes": self.param_bytes,
             "loop_ns": ((t_end or time.monotonic_ns()) - t_start
                         if t_start else 0),
         }

@@ -74,10 +74,12 @@ def _single(topo, fn, *tree_args):
 
 
 def _params_and_cache(cfg, slots):
+    """The tree the engine's programs read (decode.serving_params)."""
     from brpc_tpu.models import decode as D
     from brpc_tpu.models import transformer
-    params = jax.eval_shape(lambda k: transformer.init(k, cfg),
-                            jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        lambda k: D.serving_params(transformer.init(k, cfg), cfg),
+        jax.random.PRNGKey(0))
     cache = jax.eval_shape(lambda: D.init_cache(cfg, slots, MAX_LEN))
     return params, cache
 
@@ -115,7 +117,8 @@ def test_decode_step_compiles_tp4_on_v5e_2x2(topo):
     cfg = _gpt2_small()
     mesh = Mesh(np.array(topo.devices[:4]), ("tp",))
     params, cache = _params_and_cache(cfg, SLOTS)
-    specs = param_specs(cfg)
+    specs = dict(param_specs(cfg))
+    specs["head"] = specs["embed"]       # serving_params: embed's sharding
     flat_specs = {jax.tree_util.keystr(p): s for p, s in
                   jax.tree_util.tree_flatten_with_path(
                       specs, is_leaf=lambda x: isinstance(x, P))[0]}

@@ -571,4 +571,72 @@ def test_serving_stats_on_vars(phases_run):
     assert int(portal["serving_tokens_out"]) == \
         phases_run["stats"]["tokens_out"] == 4 + 6 * 16
     assert "serving_emit_write_ns" in portal
+    assert int(portal["serving_param_bytes"]) == \
+        phases_run["stats"]["param_bytes"] > 0
     assert phases_run["second"] == "0"
+
+
+# ---------------------------------------------------------------------------
+# the engine's programs read their weights as they are held
+# ---------------------------------------------------------------------------
+
+MECHANISM_CODE = r"""
+import json, re
+import jax, jax.numpy as jnp
+from brpc_tpu import tpu_plane
+from brpc_tpu.models import decode as D, transformer
+from brpc_tpu.parallel.mesh import make_mesh
+from brpc_tpu.serving import ServingEngine
+from brpc_tpu.serving.engine import tiny_config
+from brpc_tpu.serving.kv_cache import KvBlockPlane
+
+assert tpu_plane.init(), tpu_plane.error()
+cfg = tiny_config(dtype=jnp.bfloat16)
+p32 = jax.eval_shape(lambda k: transformer.init(k, cfg), jax.random.PRNGKey(0))
+out = {"param_bytes": D.param_bytes(
+    jax.eval_shape(lambda p: D.serving_params(p, cfg), p32))}
+for name, mesh in (("one", None), ("mesh", make_mesh({"dp": 2, "tp": 4}))):
+    engine = ServingEngine(cfg=cfg, mesh=mesh, n_slots=2, max_waiting=1,
+                           kv=KvBlockPlane(block_bytes=4096, n_blocks=8))
+    toks, active = jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool)
+    text = engine._jstep.lower(engine.params, engine.cache, toks,
+                               active).as_text()
+    text += engine._prefill_fn(12).lower(
+        engine.params, jnp.zeros((1, 12), jnp.int32)).as_text()
+    out[name] = {"converts": re.findall(
+                     r"stablehlo\.convert [^:]*: \(tensor<([^>]*)>\)", text),
+                 "param_bytes": engine.stats()["param_bytes"]}
+    engine.stop()
+print("MECH " + json.dumps(out))
+"""
+
+
+def test_programs_cast_no_weight():
+    """Lowered for a bf16 model, on one device and on the CPU mesh, the
+    engine's decode step and prefill convert no weight: no StableHLO
+    convert takes a float32 operand shaped like a weight stack, one
+    layer's weight, or the embedding.  Activations still convert."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.models import decode as D
+    from brpc_tpu.models import transformer
+    from brpc_tpu.serving.engine import tiny_config
+    _need_fake()
+    r = _run(MECHANISM_CODE, env_extra=SERVE_ENV)
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("MECH ")]
+    assert r.returncode == 0 and line, \
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    out = __import__("json").loads(line[-1][len("MECH "):])
+    cfg = tiny_config(dtype=jnp.bfloat16)
+    p32 = jax.eval_shape(lambda k: transformer.init(k, cfg),
+                         jax.random.PRNGKey(0))
+    shapes = [p32["embed"].shape]
+    for name in D.MATMUL_WEIGHTS:
+        stack = p32["blocks"][name].shape
+        shapes += [stack, stack[1:]]
+    weights = {"x".join(map(str, s)) + "xf32" for s in shapes}
+    for mesh in ("one", "mesh"):
+        got = out[mesh]["converts"]
+        assert got, mesh                  # the pattern finds converts
+        assert not weights & set(got), (mesh, sorted(weights & set(got)))
+        assert out[mesh]["param_bytes"] == out["param_bytes"]
