@@ -16,6 +16,7 @@ from brpc_tpu.models.transformer import (  # noqa: F401
     param_specs,
     place_params,
 )
+from brpc_tpu.models.mla_moe import MlaMoeConfig  # noqa: F401
 from brpc_tpu.models.decode import (  # noqa: F401
     decode_step,
     init_cache,

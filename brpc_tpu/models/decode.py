@@ -18,9 +18,17 @@ weights and a tied LM head `head` already in cfg.dtype, cast once when
 the engine takes its parameters, never inside a program.  The embedding
 table the gather reads, positions and LayerNorm stay float32.
 
-Dense-only (cfg.n_experts == 0): the serving path drives the dense
-transformer; MoE decode is an optimization path, not a serving
-requirement.
+Every architecture serves through the same functions, which dispatch by
+config type (`_arch`, which `install` asks of the cache instead): this
+file holds the dense transformer of
+models/transformer.py (`ModelConfig`); models/mla_moe.py holds latent
+attention with routed and shared experts (`MlaMoeConfig`).  What a
+sequence carries from prefill to decode is its *state*: a tuple of
+per-token arrays [L, S, ...], (k, v) for the dense transformer and
+(latent,) for MLA.  `prefill` returns it, `kv_to_bytes`/`kv_from_bytes`
+carry it over the KV block plane, and `install` writes it into the
+cache's state leaves.  A step may return counters after its cache
+(`step_counters`); the dense transformer's returns none.
 """
 
 from __future__ import annotations
@@ -32,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from brpc_tpu.models import mla_moe
 from brpc_tpu.models.transformer import ModelConfig, _cs, _layernorm
 
 # K/V serializes host-side as f32 (numpy has no bfloat16); the cache
@@ -39,9 +48,24 @@ from brpc_tpu.models.transformer import ModelConfig, _cs, _layernorm
 KV_WIRE_DTYPE = np.float32
 
 
+# the other architectures: each gives serves(config or cache), prefill,
+# decode_step, init_cache, install, serving_params, state_shapes and
+# STEP_COUNTERS
+ARCHS = (mla_moe,)
+
+
+def _arch(of):
+    """The module that serves `of`, a config or a cache, if this file
+    does not."""
+    return next((a for a in ARCHS if a.serves(of)), None)
+
+
 def _check_dense(cfg: ModelConfig) -> None:
     if cfg.n_experts > 0:
-        raise ValueError("decode path is dense-only (cfg.n_experts == 0)")
+        raise ValueError(
+            "ModelConfig.n_experts > 0 names experts, but the transformer "
+            "this file serves has no expert layer; an MoE model serves as "
+            "an mla_moe.MlaMoeConfig")
 
 
 # the block weights that enter a matmul; with `head` they are what
@@ -49,25 +73,46 @@ def _check_dense(cfg: ModelConfig) -> None:
 MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo", "w1", "w2")
 
 
-def kv_bytes_per_token(cfg: ModelConfig) -> int:
+def state_shapes(cfg, length: int) -> Tuple[Tuple[int, ...], ...]:
+    """The shapes of a `length`-token sequence's state arrays: k and v
+    [L, length, H, hd] for the dense transformer."""
+    arch = _arch(cfg)
+    if arch is not None:
+        return arch.state_shapes(cfg, length)
+    _check_dense(cfg)
+    shape = (cfg.n_layers, length, cfg.n_heads, cfg.head_dim)
+    return (shape, shape)
+
+
+def kv_bytes_per_token(cfg) -> int:
     """Host-wire bytes one token position contributes to a sequence's
-    K/V: 2 (k+v) x L x H x head_dim f32 values."""
-    return 2 * cfg.n_layers * cfg.n_heads * cfg.head_dim * \
+    state: its f32 values (2 x L x H x head_dim for the dense
+    transformer, L x (kv_rank + qk_rope) for MLA)."""
+    return sum(int(np.prod(s)) for s in state_shapes(cfg, 1)) * \
         KV_WIRE_DTYPE().itemsize
+
+
+def step_counters(cfg) -> Tuple[str, ...]:
+    """Names of the counters decode_step returns after its cache."""
+    arch = _arch(cfg)
+    return arch.STEP_COUNTERS if arch is not None else ()
 
 
 # ---------------------------------------------------------------------------
 # the serving tree
 
 
-def serving_params(params: Dict, cfg: ModelConfig) -> Dict:
+def serving_params(params: Dict, cfg) -> Dict:
     """A transformer.init()-layout tree -> the tree prefill() and
     decode_step() read: the block matmul weights in cfg.dtype, plus `head`,
     the tied LM head, = embed in cfg.dtype.  `embed` (the gather), `pos`
     and the LayerNorm leaves stay as they are, since they enter float32
     math.  A cast keeps its leaf's sharding (`head` takes embed's), so
     place the tree before this; with cfg.dtype float32 every leaf is the
-    one it was."""
+    one it was.  Another architecture's tree: its own rule."""
+    arch = _arch(cfg)
+    if arch is not None:
+        return arch.serving_params(params, cfg)
     _check_dense(cfg)
     blocks = dict(params["blocks"])
     for name in MATMUL_WEIGHTS:
@@ -100,10 +145,14 @@ def _check_serving(params: Dict, cfg: ModelConfig) -> None:
 # cache
 
 
-def init_cache(cfg: ModelConfig, slots: int, max_len: int,
+def init_cache(cfg, slots: int, max_len: int,
                mesh: Optional[Mesh] = None) -> Dict:
-    """Slot-based decode cache: k/v [L, slots, max_len, H, hd] in
-    cfg.dtype plus per-slot valid length `pos` [slots] int32."""
+    """Slot-based decode cache: state leaves [L, slots, ...] in cfg.dtype
+    (k/v [L, slots, max_len, H, hd] here) plus per-slot valid length `pos`
+    [slots] int32."""
+    arch = _arch(cfg)
+    if arch is not None:
+        return arch.init_cache(cfg, slots, max_len, mesh)
     _check_dense(cfg)
     shape = (cfg.n_layers, slots, max_len, cfg.n_heads, cfg.head_dim)
     cache = {
@@ -116,15 +165,15 @@ def init_cache(cfg: ModelConfig, slots: int, max_len: int,
     return cache
 
 
-def cache_max_len(cache: Dict) -> int:
-    return cache["k"].shape[2]
-
-
-def install(cache: Dict, slot: int, k, v, length: int) -> Dict:
-    """Make a migrated sequence's prefill K/V visible to decode_step:
-    write k/v [L, S, H, hd] into `slot` at positions [0:S] and set the
-    slot's valid length.  Eager (runs once per admit, outside the jitted
-    step)."""
+def install(cache: Dict, slot: int, state: Tuple, length: int) -> Dict:
+    """Make a migrated sequence's prefill state visible to decode_step:
+    write it into `slot` at positions [0:S] (k/v [L, S, H, hd] here) and
+    set the slot's valid length.  Eager (runs once per admit, outside the
+    jitted step)."""
+    arch = _arch(cache)
+    if arch is not None:
+        return arch.install(cache, slot, state, length)
+    k, v = state
     k = jnp.asarray(k, cache["k"].dtype)
     v = jnp.asarray(v, cache["v"].dtype)
     s = int(k.shape[1])
@@ -147,15 +196,17 @@ def reset_slot(cache: Dict, slot: int) -> Dict:
 # prefill
 
 
-def prefill(params: Dict, tokens, cfg: ModelConfig,
-            mesh: Optional[Mesh] = None) -> Tuple:
+def prefill(params: Dict, tokens, cfg, mesh: Optional[Mesh] = None) -> Tuple:
     """tokens [B, S] int32 -> (last-position logits [B, vocab] f32,
-    k [L, B, S, H, hd], v [L, B, S, H, hd]).
+    state), the state arrays [L, B, S, ...]: (k, v) [L, B, S, H, hd] here.
 
     Same math as transformer.apply()'s gather branch, but inference-mode
     (no checkpoint) and the per-layer K/V survives as the migration
     payload instead of dying with the activations.  `params` is a
     serving_params() tree."""
+    arch = _arch(cfg)
+    if arch is not None:
+        return arch.prefill(params, tokens, cfg, mesh)
     _check_dense(cfg)
     _check_serving(params, cfg)
     B, S = tokens.shape
@@ -191,23 +242,27 @@ def prefill(params: Dict, tokens, cfg: ModelConfig,
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     last = x[:, -1]
     logits = jnp.einsum("bd,vd->bv", last.astype(cfg.dtype), params["head"])
-    return (logits.astype(jnp.float32),
-            jnp.stack(ks), jnp.stack(vs))
+    return logits.astype(jnp.float32), (jnp.stack(ks), jnp.stack(vs))
 
 
 # ---------------------------------------------------------------------------
 # decode
 
 
-def decode_step(params: Dict, cache: Dict, tokens, active,
-                cfg: ModelConfig, mesh: Optional[Mesh] = None) -> Tuple:
+def decode_step(params: Dict, cache: Dict, tokens, active, cfg,
+                mesh: Optional[Mesh] = None) -> Tuple:
     """One token for every slot: tokens [N] int32 (last emitted token per
-    slot), active [N] bool -> (logits [N, vocab] f32, new cache).
+    slot), active [N] bool -> (logits [N, vocab] f32, new cache, then the
+    architecture's step_counters as one int32 vector, if it has any: the
+    dense transformer has none).
 
     Inactive slots still flow through the math (static shapes) but their
     `pos` does not advance and their scatter lands on a clamped index the
     next install overwrites — the batcher just ignores their logits.
     `params` is a serving_params() tree."""
+    arch = _arch(cfg)
+    if arch is not None:
+        return arch.decode_step(params, cache, tokens, active, cfg, mesh)
     _check_dense(cfg)
     _check_serving(params, cfg)
     k_cache, v_cache, pos = cache["k"], cache["v"], cache["pos"]
@@ -256,16 +311,19 @@ def decode_step(params: Dict, cache: Dict, tokens, active,
 # host-wire (de)serialization — the bytes the KV block plane migrates
 
 
-def kv_to_bytes(k, v) -> bytes:
-    """[L, S, H, hd] k/v pair -> contiguous f32 host bytes (k then v)."""
-    ka = np.ascontiguousarray(np.asarray(k, KV_WIRE_DTYPE))
-    va = np.ascontiguousarray(np.asarray(v, KV_WIRE_DTYPE))
-    return ka.tobytes() + va.tobytes()
+def kv_to_bytes(state: Tuple) -> bytes:
+    """A sequence's state arrays [L, S, ...] -> contiguous f32 host bytes,
+    one array after the other."""
+    return b"".join(np.ascontiguousarray(np.asarray(a, KV_WIRE_DTYPE))
+                    .tobytes() for a in state)
 
 
-def kv_from_bytes(data: bytes, cfg: ModelConfig, length: int) -> Tuple:
+def kv_from_bytes(data: bytes, cfg, length: int) -> Tuple:
     """Inverse of kv_to_bytes for a `length`-token sequence."""
-    shape = (cfg.n_layers, length, cfg.n_heads, cfg.head_dim)
-    n = int(np.prod(shape))
-    flat = np.frombuffer(data, KV_WIRE_DTYPE, count=2 * n)
-    return flat[:n].reshape(shape), flat[n:].reshape(shape)
+    out, off = [], 0
+    for shape in state_shapes(cfg, length):
+        n = int(np.prod(shape))
+        out.append(np.frombuffer(data, KV_WIRE_DTYPE, count=n,
+                                 offset=off).reshape(shape))
+        off += n * KV_WIRE_DTYPE().itemsize
+    return tuple(out)

@@ -73,15 +73,16 @@ TOKEN_FMT = "<I"  # one generated token = 4 LE bytes on the stream
 # the decode loop's leaf phases, each a `<phase>_ns` counter in stats():
 #   idle        waiting for work (sched.work.wait)
 #   prefill     the prefill program dispatched
-#   kv_d2h      prefill K/V to host bytes (decode.kv_to_bytes), which
-#               waits for the prefill program on the device
+#   kv_d2h      the prefill's state (K/V, or MLA's latent) to host bytes
+#               (decode.kv_to_bytes), which waits for the prefill program
+#               on the device
 #   kv_alloc    the bytes into pool blocks (KvBlockPlane.seq_alloc)
 #   kv_migrate  the blocks to the decode device (seq_migrate)
 #   kv_fetch    the migrated blocks back to host bytes (seq_fetch)
 #   kv_install  bytes to arrays and decode.install dispatched
 #   dispatch    a decode step's inputs built and its program dispatched
 #   sync        argmax of the logits copied to the host (every step and
-#               every admission's first token)
+#               every admission's first token), with the step's counters
 #   emit_write  one token written to its stream (no span)
 #   kv_grow     one more KV block charged (KvBlockPlane.seq_grow)
 #   retire      a sequence retired (_end)
@@ -162,6 +163,8 @@ class ServingEngine:
             lambda p, c, t, a: D.decode_step(p, c, t, a, self.cfg,
                                              self.mesh))
         self._jprefill: Dict[int, object] = {}   # prompt len -> jitted fn
+        # the step's own counters (decode.step_counters), summed over steps
+        self.step_counts = dict.fromkeys(D.step_counters(self.cfg), 0)
         self._seq_ids = iter(range(1, 1 << 62))
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -284,6 +287,7 @@ class ServingEngine:
                         if t_start else 0),
         }
         d.update({f"{k}_ns": v for k, v in self.phase_ns.items()})
+        d.update(self.step_counts)
         d.update(self.kv.stats())
         return d
 
@@ -325,8 +329,9 @@ class ServingEngine:
         return fn
 
     def _admit(self, seq: S.Sequence) -> None:
-        """Prefill one admitted sequence: compute K/V, charge blocks,
-        migrate prefill→decode device, install, emit the first token."""
+        """Prefill one admitted sequence: compute its state, charge
+        blocks, migrate prefill→decode device, install, emit the first
+        token."""
         if seq.cntl is not None and seq.cntl.is_canceled():
             self._end(seq, S.CANCELED, "canceled before prefill")
             return
@@ -338,9 +343,9 @@ class ServingEngine:
             # small, 16 slots, one v5e)
             with self._phase("prefill"):
                 toks = jnp.asarray([seq.prompt], jnp.int32)
-                logits, k, v = self._prefill_fn(plen)(self.params, toks)
+                logits, state = self._prefill_fn(plen)(self.params, toks)
             with self._phase("kv_d2h"):
-                kvb = D.kv_to_bytes(k[:, 0], v[:, 0])
+                kvb = D.kv_to_bytes(tuple(a[:, 0] for a in state))
             try:
                 with self._phase("kv_alloc"):
                     self.kv.seq_alloc(seq.seq_id, kvb)
@@ -355,8 +360,9 @@ class ServingEngine:
             with self._phase("kv_fetch"):
                 kvb = self.kv.seq_fetch(seq.seq_id)
             with self._phase("kv_install"):
-                k2, v2 = D.kv_from_bytes(kvb, self.cfg, plen)
-                self.cache = D.install(self.cache, seq.slot, k2, v2, plen)
+                self.cache = D.install(self.cache, seq.slot,
+                                       D.kv_from_bytes(kvb, self.cfg, plen),
+                                       plen)
             self.prefills += 1
             with self._phase("sync"):
                 first = int(np.asarray(jnp.argmax(logits[0])))
@@ -373,12 +379,17 @@ class ServingEngine:
                 for s in batch:
                     tokens[s.slot] = s.last_token
                     active[s.slot] = True
-                logits, self.cache = self._jstep(self.params, self.cache,
-                                                 jnp.asarray(tokens),
-                                                 jnp.asarray(active))
+                logits, self.cache, *counts = self._jstep(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(active))
             self.steps += 1
             with self._phase("sync"):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                # one wait for the argmax and the counters together
+                nxt, counts = jax.device_get(
+                    (jnp.argmax(logits, axis=-1), counts))
+                for key, n in zip(self.step_counts,
+                                  counts[0] if counts else ()):
+                    self.step_counts[key] += int(n)
             with TraceAnnotation("serving/emit"):
                 for s in batch:
                     if s.state != S.RUNNING:

@@ -199,10 +199,11 @@ class GreedyReference:
         import jax.numpy as jnp
         import numpy as np
         from brpc_tpu.models import decode as D
-        first, k, v = self._prefill(self.params,
-                                    jnp.asarray([prompt], jnp.int32))
+        first, state = self._prefill(self.params,
+                                     jnp.asarray([prompt], jnp.int32))
         cache = D.init_cache(self.cfg, self.n_slots, self.max_len)
-        cache = D.install(cache, 0, k[:, 0], v[:, 0], len(prompt))
+        cache = D.install(cache, 0, tuple(a[:, 0] for a in state),
+                          len(prompt))
         rows = [np.asarray(first[0])]
         active = np.zeros((self.n_slots,), bool)
         active[0] = True

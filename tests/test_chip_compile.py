@@ -1,6 +1,7 @@
 """The main path's programs compiled for a described TPU v5e, at GPT-2-small
-width (on-chip-measurement §2, rehearsal 3): what the chip's compiler
-refuses, or what does not fit 16 GB of HBM, fails here at no chip time.
+width and at DeepSeek-V2-Lite's (one chip's share of its four-chip
+deployment): what the chip's compiler refuses, or what does not fit 16 GB
+of HBM, fails here at no chip time.
 
 Nothing runs: the programs are compiled from shapes.  The topology is
 described inside a module fixture, never while a module is imported, so
@@ -136,3 +137,63 @@ def test_decode_step_compiles_tp4_on_v5e_2x2(topo):
     _fits(compiled)
     hlo = compiled.as_text()
     assert "all-reduce" in hlo or "reduce-scatter" in hlo
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V2-Lite, 16 of 64 experts held: the dsv2lite_sharegpt cell's
+# programs at its sizes
+
+DS_SLOTS, DS_PROMPT = 32, 512
+
+
+def _deepseek(topo):
+    """The program's config, the serving tree's shapes on one described
+    chip (made by the benchmark's family, in bf16), and that sharding."""
+    import json
+    from benchmark.spec import ROOT, load_module
+    fam = load_module(os.path.join(ROOT, "benchmark", "models",
+                                   "deepseek_v2.py"), "family_deepseek_v2")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "deepseek-v2-lite-ep4.json")) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _shapes(jax.eval_shape(lambda: fam.weights(config, 0)),
+                     lambda _p: one)
+    return fam.program_model(config), params, one
+
+
+def _pool_worst(cfg):
+    """Every slot at its longest sequence (1024 tokens) in the KV pool's
+    16-token blocks of float32 latents."""
+    from brpc_tpu.models import decode as D
+    return DS_SLOTS * (MAX_LEN // 16) * 16 * D.kv_bytes_per_token(cfg)
+
+
+def test_deepseek_decode_step_compiles_for_one_v5e(topo):
+    """32 slots over a 1024-position latent cache: the weights (9.83 GB),
+    the cache in and out, the step's temporaries and the pool's worst
+    case (2.04 GB) fit the chip."""
+    from brpc_tpu.models import decode as D
+    cfg, params, one = _deepseek(topo)
+    cache = _shapes(jax.eval_shape(
+        lambda: D.init_cache(cfg, DS_SLOTS, MAX_LEN)), lambda _p: one)
+    toks = jax.ShapeDtypeStruct((DS_SLOTS,), jnp.int32, sharding=one)
+    active = jax.ShapeDtypeStruct((DS_SLOTS,), jnp.bool_, sharding=one)
+    compiled = jax.jit(
+        lambda p, c, t, a: D.decode_step(p, c, t, a, cfg)).lower(
+            params, cache, toks, active).compile()
+    used = _fits(compiled)
+    assert used > D.param_bytes(params) + 27 * DS_SLOTS * MAX_LEN * 576 * 2
+    assert used + _pool_worst(cfg) < HBM_BYTES, used
+
+
+def test_deepseek_prefill_compiles_for_one_v5e(topo):
+    """The traffic's longest prompt, 512 tokens, with the held experts as
+    grouped matmuls."""
+    from brpc_tpu.models import decode as D
+    cfg, params, one = _deepseek(topo)
+    toks = jax.ShapeDtypeStruct((1, DS_PROMPT), jnp.int32, sharding=one)
+    compiled = jax.jit(lambda p, t: D.prefill(p, t, cfg)).lower(
+        params, toks).compile()
+    assert _fits(compiled) + _pool_worst(cfg) < HBM_BYTES
+    assert "ragged" in compiled.as_text()

@@ -58,9 +58,10 @@ def _serve(cfg, mesh, prefill, step, params):
     cache = D.init_cache(cfg, SLOTS, cfg.max_seq, mesh)
     outs, last = [], []
     for slot, prompt in enumerate(PROMPTS):
-        logits, k, v = prefill(params, jnp.asarray([prompt], jnp.int32))
-        outs.append((logits, k, v))
-        cache = D.install(cache, slot, k[:, 0], v[:, 0], len(prompt))
+        logits, state = prefill(params, jnp.asarray([prompt], jnp.int32))
+        outs.append((logits, state))
+        cache = D.install(cache, slot, tuple(a[:, 0] for a in state),
+                          len(prompt))
         last.append(int(np.argmax(np.asarray(logits[0]))))
     tokens = [[t] for t in last]
     active = jnp.ones((SLOTS,), bool)
