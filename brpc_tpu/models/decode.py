@@ -257,7 +257,7 @@ def decode_step(params: Dict, cache: Dict, tokens, active, cfg,
     dense transformer has none).
 
     Inactive slots still flow through the math (static shapes) but their
-    `pos` does not advance and their scatter lands on a clamped index the
+    `pos` does not advance and their write lands on a clamped index the
     next install overwrites — the batcher just ignores their logits.
     `params` is a serving_params() tree."""
     arch = _arch(cfg)
@@ -266,30 +266,39 @@ def decode_step(params: Dict, cache: Dict, tokens, active, cfg,
     _check_dense(cfg)
     _check_serving(params, cfg)
     k_cache, v_cache, pos = cache["k"], cache["v"], cache["pos"]
-    N = tokens.shape[0]
     S = k_cache.shape[2]
     lp = params["blocks"]
     p = jnp.minimum(pos, S - 1)                       # write index per slot
-    rows = jnp.arange(N)
     x = params["embed"][tokens] + params["pos"][p]    # [N, D]
     x = _cs(x, mesh, P("dp", None))
-    valid = jnp.arange(S)[None, :] <= p[:, None]      # [N, S] causal window
+    past = jnp.arange(S)[None, None, :] < p[:, None, None]   # [N, 1, S]
+    scale = np.sqrt(cfg.head_dim)
+    ks, vs = [], []
 
+    # the cache is read, not written, inside the layer loop: the new
+    # token's own K/V join the softmax beside the slot's past [0, p), and
+    # go into the cache after the loop in one pass per tensor (a scatter
+    # per layer has the compiler copy the whole cache to its layout and
+    # back, four whole-cache copies a step)
     for i in range(cfg.n_layers):
         h = _layernorm(x, lp["ln1_g"][i], lp["ln1_b"][i])
         hc = h.astype(cfg.dtype)
         q = jnp.einsum("nd,dhk->nhk", hc, lp["wq"][i])
         k_new = jnp.einsum("nd,dhk->nhk", hc, lp["wk"][i])
         v_new = jnp.einsum("nd,dhk->nhk", hc, lp["wv"][i])
-        k_cache = k_cache.at[i, rows, p].set(k_new)
-        v_cache = v_cache.at[i, rows, p].set(v_new)
+        ks.append(k_new)
+        vs.append(v_new)
         q = _cs(q, mesh, P("dp", "tp", None))
-        scores = jnp.einsum("nhk,nshk->nhs", q,
-                            k_cache[i]) / np.sqrt(cfg.head_dim)
-        scores = jnp.where(valid[:, None, :], scores.astype(jnp.float32),
-                           -1e30)
-        w = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("nhs,nshk->nhk", w, v_cache[i])
+        s_past = jnp.einsum("nhk,nshk->nhs", q, k_cache[i]) / scale
+        s_new = jnp.einsum("nhk,nhk->nh", q, k_new) / scale
+        s_past = jnp.where(past, s_past.astype(jnp.float32), -1e30)
+        s_new = s_new.astype(jnp.float32)
+        top = jnp.maximum(jnp.max(s_past, -1), s_new)
+        e_past, e_new = jnp.exp(s_past - top[..., None]), jnp.exp(s_new - top)
+        o = (jnp.einsum("nhs,nshk->nhk", e_past.astype(cfg.dtype), v_cache[i],
+                        preferred_element_type=jnp.float32)
+             + e_new[..., None] * v_new.astype(jnp.float32)) \
+            / (jnp.sum(e_past, -1) + e_new)[..., None]
         x = x + jnp.einsum("nhk,hkd->nd", o.astype(cfg.dtype), lp["wo"][i])
         h = _layernorm(x, lp["ln2_g"][i], lp["ln2_b"][i])
         hf = jnp.einsum("nd,df->nf", h.astype(cfg.dtype), lp["w1"][i])
@@ -299,9 +308,10 @@ def decode_step(params: Dict, cache: Dict, tokens, active, cfg,
 
     x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
     logits = jnp.einsum("nd,vd->nv", x.astype(cfg.dtype), params["head"])
+    at_p = (jnp.arange(S) == p[:, None])[None, :, :, None, None]  # [1,N,S,1,1]
     new_cache = {
-        "k": k_cache,
-        "v": v_cache,
+        "k": jnp.where(at_p, jnp.stack(ks)[:, :, None], k_cache),
+        "v": jnp.where(at_p, jnp.stack(vs)[:, :, None], v_cache),
         "pos": pos + active.astype(jnp.int32),
     }
     return logits.astype(jnp.float32), new_cache

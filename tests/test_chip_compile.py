@@ -139,6 +139,46 @@ def test_decode_step_compiles_tp4_on_v5e_2x2(topo):
     assert "all-reduce" in hlo or "reduce-scatter" in hlo
 
 
+
+def _gpt2_xl():
+    from brpc_tpu.models.transformer import ModelConfig
+    return ModelConfig(vocab=50257, d_model=1600, n_heads=25, n_layers=48,
+                       d_ff=6400, max_seq=MAX_LEN, n_experts=0,
+                       dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("model,slots,temp_bound", [
+    # the small_sharegpt cell's step
+    (_gpt2_small, 16, 64 * 10**6),
+    # xl_decode's: its temporaries also hold the float32 embedding table's
+    # copy, 50257 x 1600 x 4 bytes (321.6 MB)
+    (_gpt2_xl, 2, 64 * 10**6 + 50257 * 1600 * 4),
+], ids=["gpt2-small", "gpt2-xl"])
+def test_decode_step_writes_cache_in_one_pass(topo, model, slots,
+                                              temp_bound):
+    """The cache is read in its own layout inside the layer loop and
+    written once after it: no instruction of the cache's shape is a copy
+    (a layout round trip) or a scatter, and the step's temporaries hold
+    no whole-cache buffer (a per-layer scatter needs 2.42 GB of them at
+    gpt2-small's shape)."""
+    import re
+    from brpc_tpu.models import decode as D
+    cfg = model()
+    params, cache = _params_and_cache(cfg, slots)
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    compiled = _single(
+        topo, lambda p, c, t, a: D.decode_step(p, c, t, a, cfg),
+        params, cache, toks, active)
+    shape = "bf16[%d,%d,%d,%d,%d]" % cache["k"].shape
+    ops = re.findall(r"= %s\{[^}]*\} ([\w-]+)\(" % re.escape(shape),
+                     compiled.as_text())
+    assert not {"copy", "scatter"} & set(ops), ops
+    assert "select" in ops, ops
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_bound, temp
+
+
 # ---------------------------------------------------------------------------
 # DeepSeek-V2-Lite, 16 of 64 experts held: the dsv2lite_sharegpt cell's
 # programs at its sizes

@@ -173,3 +173,58 @@ def test_float32_tree_is_refused():
         with pytest.raises(ValueError, match=what):
             jax.jit(lambda p, c, t, a: D.decode_step(p, c, t, a, cfg))(
                 tree, cache, toks, active)
+
+
+# the cache of the step tests below: short enough that a slot reaches
+# its last position in a few steps
+SHORT_LEN, SHORT_STEPS = 16, 4
+
+
+@pytest.mark.parametrize("lengths,active", [
+    ((5, 9), (True, True)),
+    ((5, 9), (True, False)),
+    # slot 1's last step writes at SHORT_LEN - 1, the clamp's edge
+    ((5, SHORT_LEN - SHORT_STEPS), (True, True)),
+], ids=["all_active", "one_inactive", "last_position"])
+def test_step_matches_forward_and_writes_only_at_pos(lengths, active):
+    """Float32, teacher-forced: each active slot's step logits are
+    apply()'s at that position, and the step's cache differs from the one
+    it was given only at [:, n, p_n] for each slot n, where an active
+    slot holds prefill()'s K/V of its sequence; `pos` advances only for
+    active slots."""
+    cfg = tiny_config()
+    p32 = transformer.init(jax.random.PRNGKey(3), cfg)
+    sp = D.serving_params(p32, cfg)
+    rng = np.random.default_rng(11)
+    seqs = [rng.integers(0, cfg.vocab, n + SHORT_STEPS).tolist()
+            for n in lengths]
+    want = [np.asarray(transformer.apply(p32, jnp.asarray([s]), cfg)[0])
+            for s in seqs]
+    full = [D.prefill(sp, jnp.asarray([s], jnp.int32), cfg)[1] for s in seqs]
+    cache = D.init_cache(cfg, SLOTS, SHORT_LEN)
+    for n, s in enumerate(seqs):
+        _, state = D.prefill(sp, jnp.asarray([s[:lengths[n]]], jnp.int32),
+                             cfg)
+        cache = D.install(cache, n, tuple(a[:, 0] for a in state),
+                          lengths[n])
+    step = jax.jit(lambda p, c, t, a: D.decode_step(p, c, t, a, cfg))
+    on = jnp.asarray(active)
+    for _ in range(SHORT_STEPS):
+        pos = np.asarray(cache["pos"])
+        feed = jnp.asarray([s[p] for s, p in zip(seqs, pos)], jnp.int32)
+        logits, out = step(sp, cache, feed, on)
+        np.testing.assert_array_equal(np.asarray(out["pos"]),
+                                      pos + np.asarray(active))
+        written = np.zeros(cache["k"].shape[:3], bool)
+        written[:, np.arange(SLOTS), pos] = True
+        for leaf, i in (("k", 0), ("v", 1)):
+            old, new = np.asarray(cache[leaf]), np.asarray(out[leaf])
+            np.testing.assert_array_equal(new[~written], old[~written])
+            for n in np.flatnonzero(active):
+                np.testing.assert_allclose(
+                    new[:, n, pos[n]],
+                    np.asarray(full[n][i][:, 0, pos[n]]), atol=1e-5)
+        for n in np.flatnonzero(active):
+            np.testing.assert_allclose(np.asarray(logits[n]),
+                                       want[n][pos[n]], atol=1e-5)
+        cache = out
